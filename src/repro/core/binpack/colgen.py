@@ -62,6 +62,7 @@ from .arcflow import (
 from .heuristics import first_fit_decreasing
 from .problem import BinType, InfeasibleError, Problem, Solution, build_solution
 
+from ... import telemetry
 from ...kernels import knapsack as _knap
 
 __all__ = [
@@ -111,6 +112,11 @@ class ColumnPool:
 
     def __len__(self) -> int:
         return len(self._cols)
+
+    def columns(self) -> tuple[_PoolColumn, ...]:
+        """The pool's columns, oldest first: each a bin kind's name
+        (``bt_name``) and its ``(class key, choice, copies)`` entries."""
+        return tuple(self._cols)
 
     @staticmethod
     def _catalog_sig(problem: Problem) -> tuple:
@@ -323,18 +329,22 @@ def _price_dp(
 
     Returns ``(best (N, K), counts (N, K, E))``.  This is the hot path:
     during diving the whole frontier x catalog is a single kernel call.
+    Span ``colgen.price_dp``, with the dispatch's shape: ``B`` knapsacks,
+    ``T`` steps, ``S`` grid states, ``D`` dimensions.
     """
     n_nodes, _ = duals.shape
-    n_kinds, e_n, _ = grid.weights.shape
-    values = duals[:, grid.entry_class]  # (N, E)
-    values_b = np.repeat(values, n_kinds, axis=0)  # (N*K, E)
-    weights_b = np.tile(grid.weights, (n_nodes, 1, 1))
-    caps_b = np.tile(grid.cap_levels, (n_nodes, 1))
-    bounds = np.tile(grid.fit, (n_nodes, 1))  # (N*K, E)
-    if resid is not None:
-        dem = resid[:, grid.entry_class]  # (N, E)
-        bounds = np.minimum(bounds, np.repeat(dem, n_kinds, axis=0))
-    res = _knap.price_knapsacks(values_b, weights_b, bounds, caps_b, impl=impl)
+    n_kinds, e_n, dim = grid.weights.shape
+    with telemetry.span("colgen.price_dp", B=n_nodes * n_kinds, D=dim) as attrs:
+        values = duals[:, grid.entry_class]  # (N, E)
+        values_b = np.repeat(values, n_kinds, axis=0)  # (N*K, E)
+        weights_b = np.tile(grid.weights, (n_nodes, 1, 1))
+        caps_b = np.tile(grid.cap_levels, (n_nodes, 1))
+        bounds = np.tile(grid.fit, (n_nodes, 1))  # (N*K, E)
+        if resid is not None:
+            dem = resid[:, grid.entry_class]  # (N, E)
+            bounds = np.minimum(bounds, np.repeat(dem, n_kinds, axis=0))
+        res = _knap.price_knapsacks(values_b, weights_b, bounds, caps_b, impl=impl)
+        attrs.update(T=res.steps, S=res.states)
     best = res.best.reshape(n_nodes, n_kinds)
     counts = res.counts.reshape(n_nodes, n_kinds, e_n)
     return best, counts
@@ -1133,6 +1143,30 @@ def batched_dual_prices(
     ``pricing_dispatches`` / ``pricing_rounds`` counters.
     """
     problems = list(problems)
+    with telemetry.span("colgen.certify", cells=len(problems)) as attrs:
+        return _batched_dual_prices(
+            problems, pool, attrs, max_rounds=max_rounds,
+            grid_states=grid_states, exact_budget=exact_budget, impl=impl,
+            stats_out=stats_out,
+        )
+
+
+def _batched_dual_prices(
+    problems: list[Problem],
+    pool: ColumnPool | None,
+    attrs: dict,
+    *,
+    max_rounds: int,
+    grid_states: int,
+    exact_budget: int,
+    impl: str,
+    stats_out: dict | None,
+) -> list[tuple[dict[bytes, float], float]]:
+    """`batched_dual_prices` under its ``colgen.certify`` span, whose
+    ``classes`` and ``pool_columns`` (the pool's size at the end) it sets.
+    Spans inside: ``colgen.prepare`` (class union, seed columns, grid,
+    masks), one ``colgen.master_lp`` a round, ``colgen.price_dp`` and
+    each uncached ``colgen.exact_dfs``."""
     if not problems:
         return []
     sig0 = ColumnPool._catalog_sig(problems[0])
@@ -1144,55 +1178,58 @@ def batched_dual_prices(
                 stats_out["pricing_dispatches"] = (
                     stats_out.get("pricing_dispatches", 0) + 1
                 )
+        attrs["pool_columns"] = len(pool) if pool is not None else 0
         return out
     if pool is None:
         pool = ColumnPool()
     ref = problems[0]
     pool.ensure(ref)
 
-    # Union the cells' class sets (first-appearance order: stable).
-    union_keys: list[bytes] = []
-    union_reqs: list[np.ndarray] = []
-    class_reqs_by_key: dict[bytes, np.ndarray] = {}
-    per_cell: list[tuple[list[bytes], np.ndarray] | None] = []
-    for p in problems:
-        class_reqs, demands, _members = group_items(p)
-        if not len(class_reqs):
-            per_cell.append(None)
-            continue
-        keys = [class_key(r) for r in class_reqs]
-        for k, r in zip(keys, class_reqs):
-            if k not in class_reqs_by_key:
-                class_reqs_by_key[k] = r
-                union_keys.append(k)
-                union_reqs.append(r)
-        per_cell.append((keys, np.asarray(demands, dtype=np.float64)))
-    n_classes = len(union_keys)
-    if n_classes == 0:
-        return [({}, 0.0) for _ in problems]
-    key_idx = {k: i for i, k in enumerate(union_keys)}
+    with telemetry.span("colgen.prepare"):
+        # Union the cells' class sets (first-appearance order: stable).
+        union_keys: list[bytes] = []
+        union_reqs: list[np.ndarray] = []
+        class_reqs_by_key: dict[bytes, np.ndarray] = {}
+        per_cell: list[tuple[list[bytes], np.ndarray] | None] = []
+        for p in problems:
+            class_reqs, demands, _members = group_items(p)
+            if not len(class_reqs):
+                per_cell.append(None)
+                continue
+            keys = [class_key(r) for r in class_reqs]
+            for k, r in zip(keys, class_reqs):
+                if k not in class_reqs_by_key:
+                    class_reqs_by_key[k] = r
+                    union_keys.append(k)
+                    union_reqs.append(r)
+            per_cell.append((keys, np.asarray(demands, dtype=np.float64)))
+        n_classes = len(union_keys)
+        attrs.update(classes=n_classes, pool_columns=len(pool))
+        if n_classes == 0:
+            return [({}, 0.0) for _ in problems]
+        key_idx = {k: i for i, k in enumerate(union_keys)}
 
-    coverable = _seed_singletons(
-        ref, pool, union_reqs, union_keys, class_reqs_by_key
-    )
-    grid = _discretize(ref, union_reqs, grid_states)
-    zero_price = _zero_price_mask(ref, union_reqs, coverable)
+        coverable = _seed_singletons(
+            ref, pool, union_reqs, union_keys, class_reqs_by_key
+        )
+        grid = _discretize(ref, union_reqs, grid_states)
+        zero_price = _zero_price_mask(ref, union_reqs, coverable)
 
-    # Per-cell master RHS over the union classes (absent classes at 0).
-    rows = [i for i, pc in enumerate(per_cell) if pc is not None]
-    lp_demands = np.zeros((len(rows), n_classes))
-    for row, i in enumerate(rows):
-        keys, demands = per_cell[i]  # type: ignore[misc]
-        for k, d in zip(keys, demands):
-            lp_demands[row, key_idx[k]] = d
-    lp_demands[:, ~coverable] = 0.0
+        # Per-cell master RHS over the union classes (absent classes at 0).
+        rows = [i for i, pc in enumerate(per_cell) if pc is not None]
+        lp_demands = np.zeros((len(rows), n_classes))
+        for row, i in enumerate(rows):
+            keys, demands = per_cell[i]  # type: ignore[misc]
+            for k, d in zip(keys, demands):
+                lp_demands[row, key_idx[k]] = d
+        lp_demands[:, ~coverable] = 0.0
 
-    costs_k = np.asarray([bt.cost for bt in ref.bin_types])
-    caps = [
-        np.asarray(ref.effective_capacity(bt), dtype=np.float64)
-        for bt in ref.bin_types
-    ]
-    exact_fit = _exact_fit_bounds(caps, grid)
+        costs_k = np.asarray([bt.cost for bt in ref.bin_types])
+        caps = [
+            np.asarray(ref.effective_capacity(bt), dtype=np.float64)
+            for bt in ref.bin_types
+        ]
+        exact_fit = _exact_fit_bounds(caps, grid)
 
     n_rows = lp_demands.shape[0]
     Y = np.zeros((n_rows, n_classes))
@@ -1212,29 +1249,31 @@ def batched_dual_prices(
         hit = dfs_cache.get(sig)
         if hit is not None:
             return hit
-        vals = y[grid.entry_class]
-        found = False
-        scale = 1.0
-        proven_all = True
-        for k, bt in enumerate(ref.bin_types):
-            val, cnt, proven, rb, extras = _exact_knapsack(
-                caps[k], grid.entry_reqs, vals,
-                exact_fit[k].astype(np.float64), exact_budget,
-                grid.entry_class, None,
-                improve_above=float(costs_k[k]) + _PRICE_EPS,
-            )
-            proven_all &= proven
-            if val > costs_k[k] + _PRICE_EPS:
-                # Improving for this cell even when another cell's DFS
-                # pooled the same pattern earlier this round: the cell's
-                # LP has not seen it yet, so it must not freeze.
-                found = True
-                for pat in [cnt] + extras:
-                    ent = _counts_to_entries(pat, grid, union_keys)
-                    pool.add(ref, bt, ent, class_reqs_by_key)
-            z = (val + 1e-9) if proven else rb
-            if z > _EPS and costs_k[k] < z:
-                scale = min(scale, max(float(costs_k[k]), 0.0) / z)
+        with telemetry.span("colgen.exact_dfs") as dfs_attrs:
+            vals = y[grid.entry_class]
+            found = False
+            scale = 1.0
+            proven_all = True
+            for k, bt in enumerate(ref.bin_types):
+                val, cnt, proven, rb, extras = _exact_knapsack(
+                    caps[k], grid.entry_reqs, vals,
+                    exact_fit[k].astype(np.float64), exact_budget,
+                    grid.entry_class, None,
+                    improve_above=float(costs_k[k]) + _PRICE_EPS,
+                )
+                proven_all &= proven
+                if val > costs_k[k] + _PRICE_EPS:
+                    # Improving for this cell even when another cell's DFS
+                    # pooled the same pattern earlier this round: the cell's
+                    # LP has not seen it yet, so it must not freeze.
+                    found = True
+                    for pat in [cnt] + extras:
+                        ent = _counts_to_entries(pat, grid, union_keys)
+                        pool.add(ref, bt, ent, class_reqs_by_key)
+                z = (val + 1e-9) if proven else rb
+                if z > _EPS and costs_k[k] < z:
+                    scale = min(scale, max(float(costs_k[k]), 0.0) / z)
+            dfs_attrs["proven"] = bool(proven_all)
         out = (found, max(scale, 0.0), proven_all)
         dfs_cache[sig] = out
         return out
@@ -1242,23 +1281,27 @@ def batched_dual_prices(
     for _round in range(max_rounds):
         if not active:
             break
-        pat_counts, pat_costs, _reps = pool.project(ref, union_keys)
-        if not pat_counts:
-            break  # nothing coverable: every price is 0
-        pat_mat = np.asarray(pat_counts, dtype=np.float64).reshape(
-            len(pat_counts), n_classes
-        )
-        pat_cost_arr = np.asarray(pat_costs, dtype=np.float64)
-        # Cells with identical demand vectors share one LP solve.
-        lp_cache: dict[bytes, np.ndarray] = {}
-        for row in active:
-            dem_sig = lp_demands[row].tobytes()
-            y = lp_cache.get(dem_sig)
-            if y is None:
-                y, _x = _covering_lp(pat_mat, pat_cost_arr, lp_demands[row])
-                y = np.where(zero_price, 0.0, y)
-                lp_cache[dem_sig] = y
-            Y[row] = y
+        with telemetry.span("colgen.master_lp", active=len(active)) as lp_attrs:
+            pat_counts, pat_costs, _reps = pool.project(ref, union_keys)
+            if not pat_counts:
+                break  # nothing coverable: every price is 0
+            pat_mat = np.asarray(pat_counts, dtype=np.float64).reshape(
+                len(pat_counts), n_classes
+            )
+            pat_cost_arr = np.asarray(pat_costs, dtype=np.float64)
+            # Cells with identical demand vectors share one LP solve.
+            lp_cache: dict[bytes, np.ndarray] = {}
+            for row in active:
+                dem_sig = lp_demands[row].tobytes()
+                y = lp_cache.get(dem_sig)
+                if y is None:
+                    y, _x = _covering_lp(pat_mat, pat_cost_arr, lp_demands[row])
+                    y = np.where(zero_price, 0.0, y)
+                    lp_cache[dem_sig] = y
+                Y[row] = y
+            lp_attrs.update(
+                solves=len(lp_cache), cache_hits=len(active) - len(lp_cache)
+            )
         # ONE dispatch: every active cell x bin kind priced together.
         best, counts = _price_dp(grid, Y[active], None, impl)
         dfs_cache.clear()  # the pool changed since last round's DFS runs
@@ -1300,6 +1343,7 @@ def batched_dual_prices(
     for row in active:
         _found, scale, _proven = _dfs_price(Y[row])
         scale_rows[row] = scale
+    attrs["pool_columns"] = len(pool)
 
     results: list[tuple[dict[bytes, float], float]] = []
     row = 0

@@ -123,6 +123,7 @@ from typing import Sequence
 
 import numpy as np
 
+from .. import telemetry
 from .binpack import arcflow, bincompletion, heuristics
 from .binpack.problem import (
     BinType,
@@ -388,12 +389,19 @@ class FleetController:
         unplaceable (e.g. a rate no device can reach); after any exception
         mid-replan the controller's state is stale — call `reset` before
         further events.
+
+        Span ``controller.apply``, with the result's ``mode`` and the
+        exact sub-solve's ``nodes``; a re-plan's greedy repair, exact
+        sub-solve and certified bound are ``controller.repair``,
+        ``controller.exact`` and ``controller.lower_bound`` inside it.
         """
-        self.now = max(self.now, event.at)
-        result = self.policy.on_event(self, event, self._fold(event))
-        self._flush_spare_releases()
-        self._sync_lifecycle()
-        return dataclasses.replace(result, at=self.now)
+        with telemetry.span("controller.apply") as attrs:
+            self.now = max(self.now, event.at)
+            result = self.policy.on_event(self, event, self._fold(event))
+            self._flush_spare_releases()
+            self._sync_lifecycle()
+            attrs.update(mode=result.mode, nodes=result.nodes)
+            return dataclasses.replace(result, at=self.now)
 
     def _fold(self, event: FleetEvent) -> ReplanResult:
         """The mechanism half of `apply`: fold one event, no policy."""
@@ -1117,20 +1125,24 @@ class FleetController:
 
         # Greedy repair scored in one batched dispatch, then the exact
         # pinned sub-solve seeded with it as warm-start incumbent.
-        repair_placements, repair_opened = self._greedy_repair(
-            sub_problem, pinned
-        )
+        with telemetry.span("controller.repair"):
+            repair_placements, repair_opened = self._greedy_repair(
+                sub_problem, pinned
+            )
         incumbent = bincompletion.pinned_solution(
             sub_problem, pinned, repair_placements, repair_opened
         )
-        sol, stats = bincompletion.solve(
-            sub_problem,
-            max_nodes=self.sub_max_nodes,
-            incumbent=incumbent,
-            pinned=pinned,
-        )
+        with telemetry.span("controller.exact") as attrs:
+            sol, stats = bincompletion.solve(
+                sub_problem,
+                max_nodes=self.sub_max_nodes,
+                incumbent=incumbent,
+                pinned=pinned,
+            )
+            attrs["nodes"] = stats.nodes
         nodes = stats.nodes
-        lb = self._lower_bound(problem)
+        with telemetry.span("controller.lower_bound"):
+            lb = self._lower_bound(problem)
         gap = _gap(sol.cost, lb)
 
         # Adopt the warm (pinned) solution into the bin states; the full
@@ -1149,7 +1161,8 @@ class FleetController:
             )
             self._adopt_solution(problem, full_sol, match_old=True)
             self._refresh_prices(problem)
-            lb = self._lower_bound(problem)
+            with telemetry.span("controller.lower_bound"):
+                lb = self._lower_bound(problem)
             gap = _gap(full_sol.cost, lb)
 
         self._streams = new_streams

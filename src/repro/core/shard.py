@@ -41,6 +41,7 @@ import dataclasses
 import zlib
 from typing import Callable, Hashable, Sequence
 
+from .. import telemetry
 from .binpack import arcflow, colgen, heuristics
 from .binpack.problem import InfeasibleError, Problem, Solution
 from .binpack.colgen import ColumnPool
@@ -330,6 +331,12 @@ class ShardedController:
         return len(self._cells)
 
     @property
+    def column_pool(self) -> ColumnPool:
+        """The column pool every cell's certification shares; read its
+        columns through `ColumnPool.columns`."""
+        return self._colgen_pool
+
+    @property
     def fleet(self) -> tuple[StreamSpec, ...]:
         out: list[StreamSpec] = []
         for c in self._cells.values():
@@ -527,8 +534,24 @@ class ShardedController:
         ``with_snapshots=True`` additionally returns, per event, the
         merged post-event facade state the simulator replays
         (``{"uids", "rungs", "parked", "tiers"}``) as a second list.
+
+        Span ``shard.apply_events``, a root, with the batch's ``events``,
+        its ``runs`` and its ``barriers``; each cell's fold of an event is
+        a ``controller.apply`` inside it, and the per-event results are
+        rebuilt under ``shard.reconstruct``.
         """
         events = list(events)
+        with telemetry.span("shard.apply_events", events=len(events),
+                            runs=0, barriers=0) as attrs:
+            return self._apply_events(events, attrs, batched, with_snapshots)
+
+    def _apply_events(
+        self,
+        events: list[FleetEvent],
+        attrs: dict,
+        batched: bool,
+        with_snapshots: bool,
+    ):
         if not events:
             return ([], []) if with_snapshots else []
         if not batched:
@@ -580,8 +603,10 @@ class ShardedController:
             if barrier:
                 if run is not None:
                     self._fold_run(run, results, snaps)
+                    attrs["runs"] += 1
                     run = None
                 self._stats["batch_barriers"] += 1
+                attrs["barriers"] += 1
                 results[j] = self.apply(event)
                 if snaps is not None:
                     snaps[j] = self._global_snapshot()
@@ -642,6 +667,7 @@ class ShardedController:
             run.push(j, key, ("apply", event), self.now)
         if run is not None:
             self._fold_run(run, results, snaps)
+            attrs["runs"] += 1
         if with_snapshots:
             return results, snaps
         return results
@@ -782,6 +808,10 @@ class ShardedController:
         (`colgen.batched_dual_prices`) — the one-dispatch certification
         path.  ``batched=False`` (or a single cell) keeps the serial
         per-cell `FleetController.refresh_prices` loop.
+
+        Span ``shard.refresh_prices``, a root, with the live ``cells``:
+        ``colgen.certify`` inside it, then ``shard.install_prices``
+        around handing each cell its prices.
         """
         live = [
             (key, c)
@@ -789,27 +819,45 @@ class ShardedController:
             if c._problem is not None
         ]
         total = 0.0
-        if batched and len(live) > 1:
-            quotes = colgen.batched_dual_prices(
-                [c._problem for _, c in live], self._colgen_pool,
-                stats_out=self._stats,
-            )
-            for (key, c), (prices, _lp) in zip(live, quotes):
-                lb = c.install_prices(prices)
+        with telemetry.span("shard.refresh_prices", cells=len(live)):
+            if batched and len(live) > 1:
+                quotes = colgen.batched_dual_prices(
+                    [c._problem for _, c in live], self._colgen_pool,
+                    stats_out=self._stats,
+                )
+                with telemetry.span("shard.install_prices", cells=len(live)):
+                    for (key, c), (prices, _lp) in zip(live, quotes):
+                        lb = c.install_prices(prices)
+                        self._last_lb[key] = lb
+                        total += lb
+                return total
+            for key, c in live:
+                lb = c.refresh_prices()
+                self._stats["serial_price_refreshes"] += 1
                 self._last_lb[key] = lb
                 total += lb
-            return total
-        for key, c in live:
-            lb = c.refresh_prices()
-            self._stats["serial_price_refreshes"] += 1
-            self._last_lb[key] = lb
-            total += lb
         return total
 
     def stats(self) -> dict:
-        """Observability counters (a copy): event routing, merged-plan
-        segment-cache hits/misses, batched vs serial repair dispatches,
-        and pricing-dispatch counts."""
+        """Observability counters (a copy).
+
+        - ``events_routed``, ``events_per_cell``: events taken in, in all
+          and per cell; ``event_batches`` and ``batch_barriers``: calls of
+          the batched `apply_events` and the events in them that had to
+          fold eagerly.
+        - ``seg_cache_hits`` / ``seg_cache_misses``: the merged plan's
+          per-cell segment cache.
+        - ``batched_repair_dispatches``: batched pack dispatches (cold
+          resets and `repack`).  ``serial_repair_dispatches``: per-cell
+          host folds in `apply_events`' runs, one per queued op; each is a
+          fold on the host and no device dispatch.
+        - ``pricing_dispatches`` / ``pricing_rounds``: stacked pricing
+          dispatches and column-generation rounds of the batched
+          certification; ``serial_price_refreshes``: cells certified one
+          at a time.
+
+        Where the host time goes is in the spans (`repro.telemetry`).
+        """
         out = dict(self._stats)
         out["events_per_cell"] = dict(self._stats["events_per_cell"])
         return out
@@ -861,7 +909,8 @@ class ShardedController:
             from concurrent.futures import ThreadPoolExecutor
 
             with ThreadPoolExecutor(max_workers=workers) as ex:
-                for key, out in zip(keys, ex.map(fold_cell, keys)):
+                folds = ex.map(telemetry.carry(fold_cell), keys)
+                for key, out in zip(keys, folds):
                     captures[key] = out
         else:
             for key in keys:
@@ -874,60 +923,61 @@ class ShardedController:
             per_cell[key] = per_cell.get(key, 0) + n_ops
             self._stats["serial_repair_dispatches"] += n_ops
 
-        # ---- reconstruction: replay descriptors in event order --------
-        cur_plan = dict(run.base_plans)
-        cur_lb = dict(run.base_lb)
-        cur_uids = dict(run.base_uids) if snaps is not None else None
-        cur_rungs = dict(run.base_rungs) if snaps is not None else None
-        cur_parked = dict(run.base_parked) if snaps is not None else None
-        iters = {key: iter(captures[key]) for key in keys}
-        for desc in run.descs:
-            if desc[0] == "cell":
-                _kind, j, key, now_j, n_at = desc
-                r, plan_after, opsnap = next(iters[key])
-                cur_plan[key] = plan_after
-                cur_lb[key] = r.lower_bound
-                tiers_j: dict = {}
-                if snaps is not None:
-                    cur_uids[key] = opsnap[0]
-                    cur_rungs[key] = opsnap[1]
-                    cur_parked[key] = opsnap[2]
-                    tiers_j = opsnap[3]
-                if n_at == 1:
-                    results[j] = r
+        with telemetry.span("shard.reconstruct"):
+            # ---- reconstruction: replay descriptors in event order --------
+            cur_plan = dict(run.base_plans)
+            cur_lb = dict(run.base_lb)
+            cur_uids = dict(run.base_uids) if snaps is not None else None
+            cur_rungs = dict(run.base_rungs) if snaps is not None else None
+            cur_parked = dict(run.base_parked) if snaps is not None else None
+            iters = {key: iter(captures[key]) for key in keys}
+            for desc in run.descs:
+                if desc[0] == "cell":
+                    _kind, j, key, now_j, n_at = desc
+                    r, plan_after, opsnap = next(iters[key])
+                    cur_plan[key] = plan_after
+                    cur_lb[key] = r.lower_bound
+                    tiers_j: dict = {}
+                    if snaps is not None:
+                        cur_uids[key] = opsnap[0]
+                        cur_rungs[key] = opsnap[1]
+                        cur_parked[key] = opsnap[2]
+                        tiers_j = opsnap[3]
+                    if n_at == 1:
+                        results[j] = r
+                    else:
+                        results[j] = self._recon_result(
+                            cur_plan, cur_lb, now_j,
+                            mode=r.mode, displaced=r.displaced,
+                            migrated=r.migrated, nodes=r.nodes,
+                            actions=r.actions, advice=r.advice,
+                        )
                 else:
+                    _kind, j, now_j = desc
+                    tiers_j = {}
                     results[j] = self._recon_result(
-                        cur_plan, cur_lb, now_j,
-                        mode=r.mode, displaced=r.displaced,
-                        migrated=r.migrated, nodes=r.nodes,
-                        actions=r.actions, advice=r.advice,
+                        cur_plan, cur_lb, now_j, mode="noop",
                     )
-            else:
-                _kind, j, now_j = desc
-                tiers_j = {}
-                results[j] = self._recon_result(
-                    cur_plan, cur_lb, now_j, mode="noop",
-                )
-            if snaps is not None:
-                uids: list[int] = []
-                for t in cur_uids.values():
-                    uids.extend(t)
-                rungs: dict[str, int] = {}
-                for d in cur_rungs.values():
-                    rungs.update(d)
-                parked: dict[str, StreamSpec] = {}
-                for d in cur_parked.values():
-                    parked.update(d)
-                snaps[j] = {
-                    "uids": tuple(uids),
-                    "rungs": rungs,
-                    "parked": parked,
-                    "tiers": tiers_j,
-                }
-        # The reconstruction dict has serial's exact key-insertion order
-        # (new cells enter at their creation event) — adopt it, so later
-        # float sums over `_last_lb.values()` match serial bit-for-bit.
-        self._last_lb = cur_lb
+                if snaps is not None:
+                    uids: list[int] = []
+                    for t in cur_uids.values():
+                        uids.extend(t)
+                    rungs: dict[str, int] = {}
+                    for d in cur_rungs.values():
+                        rungs.update(d)
+                    parked: dict[str, StreamSpec] = {}
+                    for d in cur_parked.values():
+                        parked.update(d)
+                    snaps[j] = {
+                        "uids": tuple(uids),
+                        "rungs": rungs,
+                        "parked": parked,
+                        "tiers": tiers_j,
+                    }
+            # The reconstruction dict has serial's exact key-insertion order
+            # (new cells enter at their creation event) — adopt it, so later
+            # float sums over `_last_lb.values()` match serial bit-for-bit.
+            self._last_lb = cur_lb
 
     def _recon_result(
         self,

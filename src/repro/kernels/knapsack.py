@@ -59,6 +59,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
+
 __all__ = [
     "PricingResult",
     "build_pricing_steps",
@@ -191,6 +193,9 @@ def _jax_pmap_kernel():
 
 
 def _dp_jax(step_values, step_weights, shifts, coord):
+    """The DP on the device.  Span ``knapsack.run`` holds the transfer in
+    and the kernel, to its end on the device; ``knapsack.fetch`` the copy
+    of the take table back to the host (``bytes``)."""
     n_dev = jax.local_device_count()
     b_n = step_values.shape[0]
     with jax.enable_x64(True):
@@ -206,24 +211,30 @@ def _dp_jax(step_values, step_weights, shifts, coord):
                     a = np.concatenate([a, np.repeat(a[:1], pad, axis=0)])
                 return jnp.asarray(a.reshape((n_dev, per) + a.shape[1:]))
 
-            take = _jax_pmap_kernel()(
-                shard(step_values),
-                shard(step_weights),
-                shard(shifts),
+            with telemetry.span("knapsack.run"):
+                take = _jax_pmap_kernel()(
+                    shard(step_values),
+                    shard(step_weights),
+                    shard(shifts),
+                    jnp.asarray(coord),
+                )
+                take.block_until_ready()
+            with telemetry.span("knapsack.fetch", bytes=int(take.nbytes)):
+                # Per-device take is (T, per, S); reassemble to (T, B, S).
+                take = np.asarray(jax.device_get(take))
+                return take.transpose(1, 0, 2, 3).reshape(
+                    take.shape[1], n_dev * per, take.shape[3]
+                )[:, :b_n, :]
+        with telemetry.span("knapsack.run"):
+            take = _jax_kernel()(
+                jnp.asarray(step_values),
+                jnp.asarray(step_weights),
+                jnp.asarray(shifts),
                 jnp.asarray(coord),
             )
-            # Per-device take is (T, per, S); reassemble to (T, B, S).
-            take = np.asarray(jax.device_get(take))
-            return take.transpose(1, 0, 2, 3).reshape(
-                take.shape[1], n_dev * per, take.shape[3]
-            )[:, :b_n, :]
-        take = _jax_kernel()(
-            jnp.asarray(step_values),
-            jnp.asarray(step_weights),
-            jnp.asarray(shifts),
-            jnp.asarray(coord),
-        )
-        return np.asarray(jax.device_get(take))
+            take.block_until_ready()
+        with telemetry.span("knapsack.fetch", bytes=int(take.nbytes)):
+            return np.asarray(jax.device_get(take))
 
 
 #: Bits the largest pattern value of a dispatch may fill in fixed point;
@@ -246,31 +257,34 @@ def _fixed_point(values, bounds):
 def _dp_inputs(values, weights, bounds, cap_levels, fixed=True):
     """The DP's inputs for a non-empty batch: ``(step_values, step_weights,
     step_entry, step_mult, shifts, coord, final_idx)``, step values in
-    fixed point unless ``fixed`` is false."""
-    # Entries that cannot fit even once are dropped via a zero bound.
-    fits_once = (weights <= cap_levels[:, None, :]).all(axis=-1)
-    bounds = np.where(fits_once, bounds, 0)
-    if fixed:
-        values = _fixed_point(values, bounds)
-    steps = build_pricing_steps(values, weights, bounds)
-    _levels, strides, coord = _grid(cap_levels)
-    shifts = steps[1] @ strides  # (B, T) flat-index offset per step
-    final_idx = (cap_levels * strides[None, :]).sum(axis=1)
+    fixed point unless ``fixed`` is false.  Span ``knapsack.prepare``."""
+    with telemetry.span("knapsack.prepare"):
+        # Entries that cannot fit even once are dropped via a zero bound.
+        fits_once = (weights <= cap_levels[:, None, :]).all(axis=-1)
+        bounds = np.where(fits_once, bounds, 0)
+        if fixed:
+            values = _fixed_point(values, bounds)
+        steps = build_pricing_steps(values, weights, bounds)
+        _levels, strides, coord = _grid(cap_levels)
+        shifts = steps[1] @ strides  # (B, T) flat-index offset per step
+        final_idx = (cap_levels * strides[None, :]).sum(axis=1)
     return (*steps, shifts, coord, final_idx)
 
 
 def _backtrack(take, shifts, step_entry, step_mult, final_idx, e_n):
-    """Walk the recorded take bits into per-entry counts (B, E)."""
+    """Walk the recorded take bits into per-entry counts (B, E).  Span
+    ``knapsack.backtrack``."""
     t_n, b_n, _ = take.shape
     counts = np.zeros((b_n, e_n), dtype=np.int64)
-    for b in range(b_n):
-        s = int(final_idx[b])
-        for t in range(t_n - 1, -1, -1):
-            if take[t, b, s]:
-                e = int(step_entry[b, t])
-                if e >= 0:
-                    counts[b, e] += int(step_mult[b, t])
-                s -= int(shifts[b, t])
+    with telemetry.span("knapsack.backtrack"):
+        for b in range(b_n):
+            s = int(final_idx[b])
+            for t in range(t_n - 1, -1, -1):
+                if take[t, b, s]:
+                    e = int(step_entry[b, t])
+                    if e >= 0:
+                        counts[b, e] += int(step_mult[b, t])
+                    s -= int(shifts[b, t])
     return counts
 
 
