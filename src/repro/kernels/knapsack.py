@@ -30,6 +30,16 @@ most once.  The take bits are recorded per step and backtracked on the
 host into per-entry counts (the actual pattern / column), and ``best`` is
 that pattern's value, ``Σ_e counts · values`` summed on the host.
 
+``val[s - w]`` reads each knapsack's whole row shifted by one flat offset
+``sh = w · strides``: where ``fits`` holds, ``s - sh`` is exactly the
+flat index of ``coord - w``, and elsewhere the value read is discarded.
+The jax kernel therefore makes no per-state gather (on a TPU, XLA runs a
+gather element by element): it shifts the ``(B, S)`` block through
+``⌈log2 S⌉`` static stages, stage ``k`` shifting every row by ``2^k``
+and each row keeping the shifted or the unshifted copy by bit ``k`` of
+its own ``sh`` (`_shift_rows`).  The stage count depends on ``S`` alone,
+so shift values never change what is compiled.
+
 The DP runs on values in fixed point: int64 units of 2^-k, with k chosen
 per dispatch so the batch's largest ``Σ_e |values| · bounds`` fills 61
 bits.  Integer adds and compares are exact on every backend, whereas a
@@ -158,18 +168,41 @@ def _dp_numpy(step_values, step_weights, shifts, coord):
 # --------------------------------------------------------------------------
 
 
+def _shift_stages(s_n: int) -> int:
+    """Stages of `_shift_rows` over ``s_n`` states: enough bits for any
+    shift below ``s_n``, the only shifts whose values are kept."""
+    return (s_n - 1).bit_length()
+
+
+def _shift_rows(val, sh):
+    """``out[b, s] = val[b, s - sh[b]]`` wherever ``s >= sh[b]``; zero or
+    another row element elsewhere (bits of ``sh`` at or above
+    `_shift_stages` are ignored).  Stage ``k`` moves the whole block by the
+    constant ``2^k`` (a slice and a zero pad) and each row keeps the moved
+    copy where bit ``k`` of its shift is set: dense, static, no gather.
+    The stages commute; the highest bit goes first, which ran a full
+    certification dispatch (1536 x 18 x 6825) 9% faster on a TPU v5e than
+    the lowest first (0.119 s against 0.131 s)."""
+    s_n = val.shape[1]
+    for k in reversed(range(_shift_stages(s_n))):
+        d = 1 << k
+        moved = jnp.pad(val[:, : s_n - d], ((0, 0), (d, 0)))
+        val = jnp.where(((sh >> k) & 1)[:, None] == 1, moved, val)
+    return val
+
+
 @functools.lru_cache(maxsize=None)
 def _jax_kernel():
     def run(step_values, step_weights, shifts, coord):
         b_n, s_n = step_values.shape[0], coord.shape[0]
-        idx = jnp.arange(s_n, dtype=jnp.int64)
 
         def step(val, inp):
             v, w, sh = inp  # (B,), (B, D), (B,)
-            pred = jnp.maximum(idx[None, :] - sh[:, None], 0)
-            gathered = jnp.take_along_axis(val, pred, axis=1)
+            # val[s - sh] wherever the step fits at s; what the network
+            # leaves elsewhere is masked off by ``fits``.
+            shifted = _shift_rows(val, sh)
             fits = (coord[None, :, :] >= w[:, None, :]).all(axis=-1)
-            cand = gathered + v[:, None]
+            cand = shifted + v[:, None]
             tk = fits & (cand > val)
             return jnp.where(tk, cand, val), tk
 
@@ -194,10 +227,12 @@ def _jax_pmap_kernel():
 
 def _dp_jax(step_values, step_weights, shifts, coord):
     """The DP on the device.  Span ``knapsack.run`` holds the transfer in
-    and the kernel, to its end on the device; ``knapsack.fetch`` the copy
-    of the take table back to the host (``bytes``)."""
+    and the kernel, to its end on the device (``shift_stages``: the shift
+    network's stages a step runs); ``knapsack.fetch`` the copy of the take
+    table back to the host (``bytes``)."""
     n_dev = jax.local_device_count()
     b_n = step_values.shape[0]
+    stages = _shift_stages(coord.shape[0])
     with jax.enable_x64(True):
         if n_dev > 1 and b_n >= n_dev:
             # Multi-device fan-out: pad the batch to a device multiple,
@@ -211,7 +246,7 @@ def _dp_jax(step_values, step_weights, shifts, coord):
                     a = np.concatenate([a, np.repeat(a[:1], pad, axis=0)])
                 return jnp.asarray(a.reshape((n_dev, per) + a.shape[1:]))
 
-            with telemetry.span("knapsack.run"):
+            with telemetry.span("knapsack.run", shift_stages=stages):
                 take = _jax_pmap_kernel()(
                     shard(step_values),
                     shard(step_weights),
@@ -225,7 +260,7 @@ def _dp_jax(step_values, step_weights, shifts, coord):
                 return take.transpose(1, 0, 2, 3).reshape(
                     take.shape[1], n_dev * per, take.shape[3]
                 )[:, :b_n, :]
-        with telemetry.span("knapsack.run"):
+        with telemetry.span("knapsack.run", shift_stages=stages):
             take = _jax_kernel()(
                 jnp.asarray(step_values),
                 jnp.asarray(step_weights),

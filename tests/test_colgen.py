@@ -14,11 +14,17 @@ Three contracts pinned here:
 * **Kernel bit-equivalence** — the jax pricing DP returns
   bit-identical ``(best, counts)`` to the numpy reference across
   dtypes and shapes (hypothesis-driven when available, seeded sweep
-  otherwise).
+  otherwise), and the same take table as the numpy DP on the shift
+  network's edge cases, from a step program with no gather.
 """
+import re
+import time
+
+import jax
 import numpy as np
 import pytest
 
+from repro import telemetry
 from repro.core.binpack import (
     BinType,
     Choice,
@@ -258,6 +264,85 @@ def test_kernel_rejects_unknown_impl():
             np.ones((1, 1), dtype=np.int64),
             np.ones((1, 1), dtype=np.int64), impl="cuda",
         )
+
+
+def _dp_case(case):
+    """Step inputs ``(step_values, step_weights, shifts, coord)`` for one
+    shift-network edge case, on the grid of ``levels`` (state counts)."""
+    rng = np.random.RandomState(sum(map(ord, case)))
+    levels, b_n, t_n, dtype = {
+        "S_pow2": ((4, 8), 5, 7, np.int64),
+        "S_not_pow2": ((3, 5, 7), 5, 7, np.int64),
+        "S_1": ((1,), 3, 4, np.int64),
+        "padding_steps": ((6, 5), 4, 8, np.int64),
+        "shift_S_minus_1": ((6, 7), 4, 5, np.int64),
+        "row_shifts_distinct": ((23,), 23, 6, np.int64),
+        "float32": ((5, 6), 5, 7, np.float32),
+        "B_1": ((9, 4), 1, 9, np.int64),
+    }[case]
+    levels = np.array(levels)
+    _levels, strides, coord = knapsack._grid(levels[None, :] - 1)
+    s_n = coord.shape[0]
+    if dtype == np.int64:
+        values = rng.randint(0, 2**40, size=(b_n, t_n)).astype(np.int64)
+    else:
+        values = rng.uniform(0.0, 1.0, size=(b_n, t_n)).astype(dtype)
+    weights = rng.randint(0, 3, size=(b_n, t_n, levels.size)).astype(np.int64)
+    if case == "padding_steps":  # as build_pricing_steps pads: -1, 0
+        values[:, -3:] = -1
+        weights[:, -3:] = 0
+    elif case == "shift_S_minus_1":  # the whole capacity in one step
+        weights[:, 1] = levels - 1
+    elif case == "row_shifts_distinct":  # shift (b + t) mod S in row b
+        flat = (np.arange(b_n)[:, None] + np.arange(t_n)[None, :]) % s_n
+        weights = flat[:, :, None]
+    shifts = weights @ strides
+    return values, weights, shifts, coord
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["S_pow2", "S_not_pow2", "S_1", "padding_steps", "shift_S_minus_1",
+     "row_shifts_distinct", "float32", "B_1"],
+)
+def test_shift_network_take_table_matches_numpy(case):
+    """`_jax_kernel`'s shift network reads ``val[s - sh]`` where the
+    numpy reference gathers it: the take tables agree bit for bit."""
+    step_values, step_weights, shifts, coord = _dp_case(case)
+    s_n = coord.shape[0]
+    if case == "shift_S_minus_1":
+        assert shifts.max() == s_n - 1
+    if case == "row_shifts_distinct":
+        assert all(len(set(col)) == len(col) for col in shifts.T)
+    ref = knapsack._dp_numpy(step_values, step_weights, shifts, coord)
+    with jax.enable_x64(True):
+        got = np.asarray(knapsack._jax_kernel()(
+            step_values, step_weights, shifts, coord
+        ))
+    assert got.dtype == bool and got.shape == ref.shape
+    assert ref.any()  # something is taken, so the comparison bites
+    np.testing.assert_array_equal(got, ref)
+
+
+def test_pricing_kernel_is_gather_free_and_keeps_its_name():
+    """The DP step lowers with no gather, in the XLA module ``jit_run``
+    that the benchmark's ``pricing_roofline`` reads, and the
+    ``knapsack.run`` span records the stages a step ran."""
+    step_values, step_weights, shifts, coord = _dp_case("S_not_pow2")
+    s_n = coord.shape[0]
+    with jax.enable_x64(True):
+        lowered = knapsack._jax_kernel().lower(
+            step_values, step_weights, shifts, coord
+        )
+        hlo = lowered.compile().as_text()
+    assert "gather" not in lowered.as_text()
+    assert "gather" not in hlo
+    assert re.search(r"HloModule (\S+)", hlo).group(1).startswith("jit_run")
+
+    since = time.perf_counter()
+    knapsack._dp_jax(step_values, step_weights, shifts, coord)
+    runs = [r for r in telemetry.records(since) if r.name == "knapsack.run"]
+    assert runs[-1].attrs["shift_stages"] == (s_n - 1).bit_length() == 7
 
 
 @pytest.mark.parametrize("seed", range(6))
