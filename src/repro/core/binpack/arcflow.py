@@ -41,7 +41,7 @@ import dataclasses
 import logging
 import math
 import sys
-from typing import Sequence
+from typing import NamedTuple, Sequence
 
 import numpy as np
 
@@ -351,29 +351,37 @@ def _pattern_columns(
     return pat_counts, pat_costs, pat_reps, truncated, n_enumerated
 
 
-def _covering_lp(
-    pat_mat: np.ndarray, pat_cost: np.ndarray, demand: np.ndarray
-) -> tuple[np.ndarray, np.ndarray]:
-    """Duals and primal of min{c·x : Σ x_p·pattern_p >= d, x >= 0}.
+class _SimplexExit(NamedTuple):
+    """Where `_covering_simplex` stopped: the duals of its last basis,
+    that basis and its levels, whether it stopped optimal (no entering
+    column) and how many pivots it made."""
 
-    Revised simplex with Big-M artificials and Bland's rule; the basis is
-    only (n_classes x n_classes), so iterations are trivial.  Whatever the
-    exit path, the returned y is projected to dual feasibility
-    (pattern·y <= cost for every pattern, y >= 0), so `d'·y` is an
-    admissible completion bound for any residual demand d'.  The primal x
-    (per-pattern fractional multiplicities) seeds the rounding incumbent.
+    y: np.ndarray
+    basis: list[int]
+    x_b: np.ndarray
+    optimal: bool
+    pivots: int
+
+
+def _covering_simplex(
+    pat_mat: np.ndarray, pat_cost: np.ndarray, demand: np.ndarray
+) -> _SimplexExit:
+    """Revised simplex for min{c·x : Σ x_p·pattern_p >= d, x >= 0} from an
+    all-artificial Big-M basis, with Bland's rule.
+
+    Columns: patterns | surplus (-I, cost 0) | artificials (+I, cost M).
+    It stops optimal when no column has a reduced cost below -1e-9; a
+    singular basis, an unbounded direction or the 2000-pivot cap stop it
+    short of that.
     """
     n_pat, k = pat_mat.shape
-    if k == 0:
-        return np.zeros(0), np.zeros(n_pat)
     big_m = (float(demand.sum()) + 1.0) * (float(pat_cost.max()) + 1.0)
-    # Columns: patterns | surplus (-I, cost 0) | artificials (+I, cost M).
     cols = np.concatenate([pat_mat.T, -np.eye(k), np.eye(k)], axis=1)
     costs = np.concatenate([pat_cost, np.zeros(k), np.full(k, big_m)])
     basis = list(range(n_pat + k, n_pat + 2 * k))
     x_b = demand.astype(np.float64).copy()
     y = np.zeros(k)
-    for _ in range(2000):
+    for pivots in range(2000):
         b_mat = cols[:, basis]
         try:
             y = np.linalg.solve(b_mat.T, costs[basis])
@@ -382,7 +390,7 @@ def _covering_lp(
         reduced = costs - y @ cols
         entering_candidates = np.where(reduced < -1e-9)[0]
         if entering_candidates.size == 0:
-            break
+            return _SimplexExit(y, basis, x_b, True, pivots)
         j = int(entering_candidates[0])  # Bland's rule: smallest index
         try:
             u = np.linalg.solve(b_mat, cols[:, j])
@@ -401,7 +409,16 @@ def _covering_lp(
         x_b = x_b - step * u
         x_b[leave_pos] = step
         basis[leave_pos] = j
-    # Project to dual feasibility regardless of how the loop exited.
+    else:
+        pivots = 2000
+    return _SimplexExit(y, basis, x_b, False, pivots)
+
+
+def _feasible_duals(
+    y: np.ndarray, pat_mat: np.ndarray, pat_cost: np.ndarray
+) -> np.ndarray:
+    """``y`` projected to dual feasibility: clipped at 0, then scaled down
+    (Farley) until pattern·y <= cost for every pattern."""
     y = np.maximum(y, 0.0)
     used = y @ pat_mat.T  # (P,)
     with np.errstate(divide="ignore", invalid="ignore"):
@@ -411,11 +428,79 @@ def _covering_lp(
     scale = float(min(1.0, scale_all.min())) if scale_all.size else 1.0
     if not np.isfinite(scale) or scale < 0:
         scale = 0.0
+    return y * scale
+
+
+def _covering_lp(
+    pat_mat: np.ndarray, pat_cost: np.ndarray, demand: np.ndarray
+) -> tuple[np.ndarray, np.ndarray]:
+    """Duals and primal of min{c·x : Σ x_p·pattern_p >= d, x >= 0}.
+
+    Revised simplex with Big-M artificials and Bland's rule
+    (`_covering_simplex`); the basis is only (n_classes x n_classes), so
+    iterations are trivial.  Whatever the exit path, the returned y is
+    projected to dual feasibility (pattern·y <= cost for every pattern,
+    y >= 0), so `d'·y` is an admissible completion bound for any residual
+    demand d'.  The primal x (per-pattern fractional multiplicities) seeds
+    the rounding incumbent.
+    """
+    n_pat, k = pat_mat.shape
+    if k == 0:
+        return np.zeros(0), np.zeros(n_pat)
+    lp = _covering_simplex(pat_mat, pat_cost, demand)
     x_primal = np.zeros(n_pat)
-    for b_i, x_v in zip(basis, x_b):
+    for b_i, x_v in zip(lp.basis, lp.x_b):
         if b_i < n_pat and x_v > 1e-12:
             x_primal[b_i] = x_v
-    return y * scale, x_primal
+    return _feasible_duals(lp.y, pat_mat, pat_cost), x_primal
+
+
+def _covering_lp_rows(
+    pat_mat: np.ndarray, pat_cost: np.ndarray, demands: np.ndarray
+) -> tuple[np.ndarray, dict[str, int]]:
+    """`_covering_lp`'s duals for every row of ``demands`` (R, K) over one
+    pattern matrix, from shared optimal bases.
+
+    Whether a basis is dual feasible depends only on the patterns and
+    their costs, so a basis optimal for one row is optimal for every row d
+    with B⁻¹d >= 0 and no artificial above 0.  Rows are taken in order:
+    the first that no basis found so far fits gets a cold
+    `_covering_simplex`; if that stops optimal with no artificial above 0,
+    its basis is factored once and tried on every row still open in one
+    solve.  Each basis's y is projected once (`_feasible_duals`), so rows
+    on one basis carry bit-identical duals.  A row solved cold gets
+    exactly `_covering_lp`'s y.
+
+    Returns ``(Y, info)``: Y is (R, K); info counts ``cold_solves``,
+    ``basis_hits`` (rows answered by a basis another row found) and
+    ``pivots`` (over the cold solves).
+    """
+    n_rows, k = demands.shape
+    n_pat = pat_mat.shape[0]
+    Y = np.zeros((n_rows, k))
+    info = {"cold_solves": 0, "basis_hits": 0, "pivots": 0}
+    if k == 0:
+        return Y, info
+    cols = np.concatenate([pat_mat.T, -np.eye(k), np.eye(k)], axis=1)
+    open_rows = np.arange(n_rows)
+    while open_rows.size:
+        row, open_rows = open_rows[0], open_rows[1:]
+        lp = _covering_simplex(pat_mat, pat_cost, demands[row])
+        y = Y[row] = _feasible_duals(lp.y, pat_mat, pat_cost)
+        info["cold_solves"] += 1
+        info["pivots"] += lp.pivots
+        artificial = np.asarray(lp.basis) >= n_pat + k
+        if not (lp.optimal and open_rows.size) or (lp.x_b[artificial] > 1e-12).any():
+            continue
+        try:
+            x = np.linalg.solve(cols[:, lp.basis], demands[open_rows].T)
+        except np.linalg.LinAlgError:
+            continue
+        fits = (x >= -1e-10).all(axis=0) & (x[artificial] <= 1e-12).all(axis=0)
+        Y[open_rows[fits]] = y
+        info["basis_hits"] += int(fits.sum())
+        open_rows = open_rows[~fits]
+    return Y, info
 
 
 def solve_arcflow(

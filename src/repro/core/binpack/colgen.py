@@ -55,6 +55,7 @@ import numpy as np
 from .arcflow import (
     ArcflowStats,
     _covering_lp,
+    _covering_lp_rows,
     class_key,
     covering_search,
     group_items,
@@ -1123,7 +1124,9 @@ def batched_dual_prices(
     `price_knapsacks` dispatch covering every (cell, bin kind) knapsack.
     Per-cell restricted-master LPs stay separate (each cell's *demands*
     differ, and `rebalance` arbitrages on per-cell price differences),
-    but column generation is fleet-global: a column any cell discovers
+    but a round's LPs share one pattern matrix, so they are solved
+    together from shared optimal bases (`arcflow._covering_lp_rows`),
+    and column generation is fleet-global: a column any cell discovers
     immediately warm-starts every other cell's master.
 
     Each returned ``(prices, lp_value)`` satisfies `dual_prices`'
@@ -1289,18 +1292,21 @@ def _batched_dual_prices(
                 len(pat_counts), n_classes
             )
             pat_cost_arr = np.asarray(pat_costs, dtype=np.float64)
-            # Cells with identical demand vectors share one LP solve.
-            lp_cache: dict[bytes, np.ndarray] = {}
-            for row in active:
-                dem_sig = lp_demands[row].tobytes()
-                y = lp_cache.get(dem_sig)
-                if y is None:
-                    y, _x = _covering_lp(pat_mat, pat_cost_arr, lp_demands[row])
-                    y = np.where(zero_price, 0.0, y)
-                    lp_cache[dem_sig] = y
-                Y[row] = y
+            # Cells with identical demand vectors share one LP row; the
+            # distinct rows share optimal bases (one pattern matrix).
+            slot: dict[bytes, int] = {}
+            picks = [
+                slot.setdefault(lp_demands[row].tobytes(), len(slot))
+                for row in active
+            ]
+            firsts = np.unique(picks, return_index=True)[1]
+            Y_lp, lp_info = _covering_lp_rows(
+                pat_mat, pat_cost_arr, lp_demands[active][firsts]
+            )
+            Y_lp[:, zero_price] = 0.0
+            Y[active] = Y_lp[picks]
             lp_attrs.update(
-                solves=len(lp_cache), cache_hits=len(active) - len(lp_cache)
+                solves=len(slot), cache_hits=len(active) - len(slot), **lp_info
             )
         # ONE dispatch: every active cell x bin kind priced together.
         best, counts = _price_dp(grid, Y[active], None, impl)

@@ -244,6 +244,13 @@ def test_control_plane_spans_reach_the_profile(tmp_path):
     cert = next(r for r in recs if r.name == "colgen.certify")
     assert cert.attrs["cells"] == ctrl.n_cells
     assert cert.attrs["pool_columns"] == len(ctrl.column_pool.columns()) > 0
+    for lp in (r for r in recs if r.name == "colgen.master_lp"):
+        a = lp.attrs
+        assert set(a) == {"active", "solves", "cache_hits", "cold_solves",
+                          "basis_hits", "pivots"}
+        assert a["solves"] + a["cache_hits"] == a["active"]
+        assert a["cold_solves"] + a["basis_hits"] == a["solves"]
+        assert 1 <= a["cold_solves"] <= a["pivots"]
     fetch = [r for r in recs if r.name == "knapsack.fetch"]
     dp = [r for r in recs if r.name == "colgen.price_dp"]
     assert sum(r.attrs["bytes"] for r in fetch) == sum(
