@@ -5,11 +5,13 @@
 
 With one chip, in one process and in this order:
 
-1. control plane at deployment size: the 100,000-stream fleet over 512
-   hash cells that ``benchmarks/shard.py`` builds.  The batched cold pack
+1. control plane at deployment size: the benchmark's own deployment,
+   ``bench/configs/ec2-paper-100k.json`` (100,000 streams over 512 hash
+   cells), built by ``bench.fleet``.  The batched cold pack
    (``reset(pack="batched")``) is checked against the serial numpy pack of
-   every cell, a 192-event ``apply_events`` replay against the serial
-   per-event loop on a twin controller, and every dispatch of the stacked
+   every cell, a 192-event ``apply_events`` replay (six blocks of the
+   ``bench/traffic/recertify32.json`` mix) against the serial per-event
+   loop on a twin controller, and every dispatch of the stacked
    certification (``refresh_prices``) against the numpy pricing
    reference.  All must agree bit for bit.  The certified lower bound
    must also match, within ``LB_REL_TOL``, the one a twin controller
@@ -36,8 +38,9 @@ the XLA compiles it asked for with their seconds (persistent-cache hits
 included and counted apart), and the device's ``peak_bytes_in_use`` so
 far.  The last line is ``{"ok": true, "device": {...}}``, printed only
 when every check passed.  Without a TPU the script exits non-zero and
-prints no result.  Weights and frames come from ``--seed``; the fleet and
-its event trace are ``benchmarks/shard.py``'s own.
+prints no result.  Weights, frames and the order of the events inside
+each block come from ``--seed``; the fleet and the events are the
+benchmark's own.
 """
 from __future__ import annotations
 
@@ -54,6 +57,10 @@ import jax
 import numpy as np
 
 ROOT = pathlib.Path(__file__).resolve().parent
+CONFIG = ROOT / "bench" / "configs" / "ec2-paper-100k.json"
+TRAFFIC = ROOT / "bench" / "traffic" / "recertify32.json"
+#: Blocks of the traffic's 32-event mix replayed: 192 events.
+EVENT_BLOCKS = 6
 
 #: Allowed relative gap between the certified lower bounds of the
 #: fixed-point and the float64 pricing DP (the master LP's tolerance).
@@ -175,27 +182,15 @@ def _reference_bins(problem) -> list:
     return bins
 
 
-def control_plane(smoke: Smoke, rec: dict, *, n_streams: int, n_cells: int,
-                  n_events: int) -> None:
-    from benchmarks.shard import (
-        GAP_THRESHOLD, SEED, SUB_MAX_NODES, _events, _fleet, _manager,
-    )
-    from repro.core.shard import ShardedController, hash_cells
-    from repro.core.strategies import ST3
+def control_plane(smoke: Smoke, rec: dict, cfg: dict, traffic: dict, *,
+                  seed: int, blocks: int) -> None:
+    """The deployment ``cfg`` and ``blocks`` blocks of ``traffic``'s mix."""
+    from bench.fleet import build_controller, build_fleet, closed_loop_trace
     from repro.kernels import knapsack
 
-    def build():
-        sc = ShardedController(
-            _manager(), ST3, cell_key=hash_cells(n_cells),
-            sub_max_nodes=SUB_MAX_NODES, gap_threshold=GAP_THRESHOLD,
-        )
-        sc.reset(streams, at=0.0, pack="batched")
-        return sc
-
-    streams = _fleet(n_streams)
-    events = _events(np.random.RandomState(SEED), streams, n_events)
+    events = closed_loop_trace(cfg, traffic, build_fleet(cfg), seed, blocks)
     t0 = time.perf_counter()
-    batched = build()
+    batched = build_controller(cfg)
     rec["reset_batched_s"] = time.perf_counter() - t0
     rec["streams"], rec["cells"] = len(batched.fleet), batched.n_cells
     cells = list(batched.cells.values())
@@ -205,7 +200,7 @@ def control_plane(smoke: Smoke, rec: dict, *, n_streams: int, n_cells: int,
     rec["reset_mismatched_cells"] = bad
     smoke.check(rec, "cold_pack_matches_numpy", bad == 0)
 
-    serial = build()
+    serial = build_controller(cfg)
     t0 = time.perf_counter()
     serial_costs, serial_lbs, last = [], [], None
     for ev in events:
@@ -358,9 +353,9 @@ def serving(smoke: Smoke, rec: dict, cfg, *, seed: int, n_requests: int,
     smoke.check(rec, "decode_matches_prefill", float(excess.max()) <= 0.0)
 
 
-def fan_out(smoke: Smoke, rec: dict, *, n_streams: int, n_cells: int) -> None:
+def fan_out(smoke: Smoke, rec: dict, cfg: dict) -> None:
     """pmap over local devices vs the single-device kernels, bit for bit."""
-    from benchmarks.shard import _fleet, _manager
+    from bench.fleet import build_fleet, build_manager
     from repro.core.binpack import colgen, heuristics
     from repro.core.shard import hash_cells
     from repro.core.strategies import ST3
@@ -368,8 +363,8 @@ def fan_out(smoke: Smoke, rec: dict, *, n_streams: int, n_cells: int) -> None:
 
     rec["devices"] = jax.local_device_count()
     smoke.check(rec, "several_devices", rec["devices"] > 1)
-    key, parts, mgr = hash_cells(n_cells), {}, _manager()
-    for s in _fleet(n_streams):
+    key, parts, mgr = hash_cells(cfg["cells"]), {}, build_manager(cfg)
+    for s in build_fleet(cfg):
         parts.setdefault(key(s), []).append(s)
     probs = [mgr.formulate(part, ST3) for part in parts.values()]
     ts = [p.tensors() for p in probs]
@@ -421,10 +416,10 @@ def main() -> int:
               f"devices, found {len(devices)}", file=sys.stderr)
         return 2
     sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
-    from benchmarks.shard import CELLS_BIG, EVENTS_BIG, N_BIG
     from repro.configs import default_tokens_per_frame, get_config
     from repro.launch.compile_cache import use_compile_cache
 
+    cfg = json.loads(CONFIG.read_text())
     cache_dir = use_compile_cache()
     smoke = Smoke()
     print(json.dumps({"phase": "setup", "compile_cache": cache_dir,
@@ -433,11 +428,11 @@ def main() -> int:
           flush=True)
     if args.chips == 4:
         with smoke.phase("fan_out") as rec:
-            fan_out(smoke, rec, n_streams=N_BIG, n_cells=CELLS_BIG)
+            fan_out(smoke, rec, cfg)
     else:
         with smoke.phase("control_plane") as rec:
-            control_plane(smoke, rec, n_streams=N_BIG, n_cells=CELLS_BIG,
-                          n_events=EVENTS_BIG)
+            control_plane(smoke, rec, cfg, json.loads(TRAFFIC.read_text()),
+                          seed=args.seed, blocks=EVENT_BLOCKS)
         with smoke.phase("vgg16") as rec:
             vgg16(smoke, rec, seed=args.seed, n_frames=VGG16_FRAMES)
         with smoke.phase("serve_" + SERVE_ARCH) as rec:

@@ -9,7 +9,7 @@ Usage:
 
 This is the regeneration entry point of the calibrated-requirements loop:
 when kernels (or hardware constants, or the workload set) change, rerun it
-and every calibrated benchmark re-derives its requirement vectors from the
+and every calibrated scenario re-derives its requirement vectors from the
 new artifact.  The default analytic mode is deterministic — rerunning
 without a source change rewrites byte-identical files, which is what
 ``--check`` verifies (exit 1 when a committed artifact is stale or

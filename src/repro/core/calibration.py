@@ -6,7 +6,7 @@ analysis program's per-resource requirements on every candidate device.
 This module closes that loop for the fleet layer: it turns
 ``(program/model config, BinType)`` pairs into requirement vectors and
 packages them as a JSON-persistable :class:`CalibrationArtifact` that the
-manager, trace generators, and benchmarks consume instead of hand-written
+manager, trace generators and tests consume instead of hand-written
 numbers.
 
 Two measurement modes:
@@ -15,7 +15,7 @@ Two measurement modes:
   program's analytic FLOPs and a sustained per-core throughput recorded in
   the :class:`CpuSpec`.  Fully deterministic: the same workloads + catalog
   signature always yield bit-identical vectors (test-gated), which is what
-  lets benchmarks pin scenarios to an artifact.
+  lets tests pin scenarios to an artifact.
 * ``cpu_mode="measured"`` — real wall-clock test runs through
   :func:`repro.core.profiler.measure_cpu_profile` for programs with a
   runnable ``run_fn`` (the paper's actual procedure).  Nondeterministic by
@@ -309,7 +309,8 @@ class CalibrationArtifact:
         speedup shrinks both terms of the occupancy): the accel-compute
         requirement divides by ``factor``, memory floors and host cores are
         unchanged, and ``max_fps`` re-clamps against the same catalog.
-        This is the kernel→dollars probe used by ``benchmarks/calibration``.
+        Re-planning a fleet under it prices a faster kernel in fleet
+        dollars (``tests/test_outcome_floors.py`` holds a floor on that).
         """
         if factor <= 0.0:
             raise ValueError(f"speedup factor must be > 0, got {factor}")
@@ -733,7 +734,7 @@ def stream_mix(
 
 
 # ---------------------------------------------------------------------------
-# Presets + persistence entry points (scripts/recalibrate.py, benchmarks)
+# Presets + persistence entry points (scripts/recalibrate.py, tests)
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -799,7 +800,7 @@ def load_or_calibrate(
     impl: str = "numpy",
     cpu_mode: str = "analytic",
 ) -> CalibrationArtifact:
-    """The artifact benchmarks consume: load the persisted one if it is
+    """The artifact callers consume: load the persisted one if it is
     fresh for the preset's catalog, else recalibrate in-process.
 
     Never writes — regeneration on disk is ``scripts/recalibrate.py``'s job.

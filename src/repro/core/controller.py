@@ -106,7 +106,7 @@ drains (still serving, still billing) until its replacements boot or the
 deadline hits, whichever is first.  The paired kill then lands on an
 already-empty instance: blackout became an ordinary double-billed
 migration.  With ``drain_on_notice=False`` the warning is recorded but
-ignored — the naive baseline the storm benchmark compares against.
+ignored — the naive baseline the storm scenarios compare against.
 
 Degradation is a mechanism move too: `set_stream_rung` shrinks a
 stream's requirement vector to a lower rung of its `streams.SLATier`

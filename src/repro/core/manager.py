@@ -244,8 +244,6 @@ class ResourceManager:
                     "policy_factory",
                     "drain_on_notice",
                     "rebalance_every",
-                    "rebalance_moves",
-                    "rebalance_min_saving",
                 ):
                     setattr(ctrl, key, value)
                 else:

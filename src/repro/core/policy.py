@@ -664,8 +664,7 @@ class ActingAutoscaler(LookaheadAutoscaler):
 
     The spend is bounded: at most ``max_spares`` spares are ever held, so
     the billed overhead per event is at most ``max_spares`` times the
-    cheapest-host rent — the ≤5% overhead envelope the lifecycle
-    benchmark gates.
+    cheapest-host rent.
 
     Spares are held to *absorb* boot waits, so an unreliable spare is
     worse than none: when the packer's open rule lands on a spot type

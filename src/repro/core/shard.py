@@ -263,9 +263,6 @@ class ShardedController:
         billing_by_type: dict[str, BillingModel] | None = None,
         drain_on_notice: bool = True,
         rebalance_every: int = 0,
-        rebalance_moves: int = 4,
-        rebalance_min_saving: float = 0.0,
-        batch_workers: int = 0,
     ) -> None:
         self.manager = manager
         self.strategy = strategy
@@ -279,13 +276,6 @@ class ShardedController:
         #: Run the cross-cell rebalancing market every N applied events
         #: (0 = only when `rebalance()` is called explicitly).
         self.rebalance_every = rebalance_every
-        self.rebalance_moves = rebalance_moves
-        self.rebalance_min_saving = rebalance_min_saving
-        #: Thread-pool width for fanning independent cell folds out in
-        #: `apply_events` (0/1 = sequential).  The fold is bit-identical
-        #: either way for arcflow-priced cells; pool-sharing colgen
-        #: cells may discover columns in a different order.
-        self.batch_workers = batch_workers
         self.now = 0.0
         self._cells: dict[Hashable, FleetController] = {}
         self._cell_list: list[FleetController] = []  # creation order = stride
@@ -481,10 +471,7 @@ class ShardedController:
             and self._events_since_rebalance >= self.rebalance_every
         ):
             self._events_since_rebalance = 0
-            actions = self.rebalance(
-                max_moves=self.rebalance_moves,
-                min_saving=self.rebalance_min_saving,
-            )
+            actions = self.rebalance()
             if actions:
                 result = self._result(
                     mode=result.mode,
@@ -512,8 +499,7 @@ class ShardedController:
         order doing exactly `apply`'s routing (advancing the clock,
         creating cells, updating the name->cell and notice maps), but
         only QUEUES each event on its owning cell.  Each cell then folds
-        its queue through its warm controller back-to-back (optionally
-        across a thread pool, ``batch_workers``), and reconstruction
+        its queue through its warm controller back-to-back, and reconstruction
         re-emits one `ReplanResult` per event in original order with the
         merged plan materialized LAZILY — segment concatenation is paid
         once per accessed plan instead of once per event.
@@ -903,18 +889,7 @@ class ShardedController:
                 out.append((r, c.plan, opsnap))
             return out
 
-        captures: dict[Hashable, list[tuple]] = {}
-        workers = min(self.batch_workers, len(keys))
-        if workers > 1:
-            from concurrent.futures import ThreadPoolExecutor
-
-            with ThreadPoolExecutor(max_workers=workers) as ex:
-                folds = ex.map(telemetry.carry(fold_cell), keys)
-                for key, out in zip(keys, folds):
-                    captures[key] = out
-        else:
-            for key in keys:
-                captures[key] = fold_cell(key)
+        captures = {key: fold_cell(key) for key in keys}
         for name in pops:
             self._cell_of.pop(name, None)
         per_cell = self._stats["events_per_cell"]

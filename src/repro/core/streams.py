@@ -27,9 +27,9 @@ Real clouds warn before reclaiming spot capacity:
 the *same* instance across policies that do and do not act on notices.
 `storm_trace` composes seeded correlated-failure scenarios
 (`StormPhase`: whole-pool reclamation, notice-then-kill waves, price
-spikes, flash-crowd joins) over a background churn trace — the
-fault-injection harness `benchmarks/storms.py` replays identically
-across policies.
+spikes, flash-crowd joins) over a background churn trace, which the
+storm tests (``tests/test_sla.py``, ``tests/test_outcome_floors.py``)
+replay identically across policies.
 
 For the policy layer's lookahead autoscaler, `StreamForecast` describes a
 short-horizon join/leave forecast and `forecast_cone` expands it into the
@@ -43,7 +43,7 @@ stay valid), and `TimedTrace` is the validated container of a monotone
 event sequence plus its horizon — the input `core.simulator.simulate_churn`
 replays through the instance-lifecycle billing engine
 (`core.lifecycle.LifecycleEngine`).  `synthetic_timed_trace` generates the
-seeded join/leave/re-rate traces the benchmarks replay.
+seeded join/leave/re-rate traces the tests replay.
 """
 from __future__ import annotations
 

@@ -53,8 +53,7 @@ Two implementations share this exact op sequence and are bit-equivalent
 on ``(best, counts)``:
 
 * `price_knapsacks(..., impl="numpy")` — the reference: a Python loop
-  over batch entries (this is the "serial per-kind loop" the benchmark
-  measures against),
+  over batch entries,
 * ``impl="jax"`` (the default) — one jitted `lax.scan` over steps
   carrying the whole ``(B, S)`` state block: all kinds × nodes in one
   dispatch, fanned across local devices by `jax.pmap` when more than
@@ -142,7 +141,7 @@ def build_pricing_steps(
 
 
 # --------------------------------------------------------------------------
-# numpy reference: serial loop over batch entries (the benchmark baseline)
+# numpy reference: serial loop over batch entries (the tests' reference)
 # --------------------------------------------------------------------------
 
 def _dp_numpy(step_values, step_weights, shifts, coord):

@@ -1,6 +1,6 @@
 """Persistent XLA compilation cache for the repo's entry points.
 
-Entry points (``chip_smoke.py``, ``benchmarks.run``, ``repro.launch.serve``,
+Entry points (``chip_smoke.py``, ``repro.launch.serve``,
 ``scripts/recalibrate.py``) call `use_compile_cache` once at start-up;
 importing this module changes nothing.  A run that finds the cache warm
 skips the XLA compiles an earlier run of the same programs already paid.

@@ -9,7 +9,7 @@ all-to-all-equivalent collectives. Capacity drops follow GShard/Switch
 semantics (priority = routing order); dropped pairs renormalize over the
 surviving ones.
 
-Perf notes (EXPERIMENTS.md §Perf, qwen3 train_4k iteration):
+Perf notes (qwen3 train_4k iteration):
 
 * **Grouped dispatch** — slot positions need a running count of tokens per
   expert. A single global cumsum over (T·k, E) is a sequential scan over

@@ -205,7 +205,7 @@ def forward_train(params: dict, cfg: ModelConfig, batch: dict,
     inter-group residual stream). ``act_spec`` (a PartitionSpec) pins the
     residual-stream sharding inside the depth scan — without it the
     remat-saved carry stack loses its sharding and balloons per-device
-    memory (found via dry-run memory_analysis; see EXPERIMENTS.md §Perf).
+    memory (found via dry-run memory_analysis).
     """
     x = embed_inputs(params, cfg, batch)
     s = x.shape[1]

@@ -168,7 +168,7 @@ def apply_fsdp(specs: Any, params: Any, *, fsdp_axes=("data",),
         # Largest still-replicated dim. (Sharding the leading layer-stack
         # axis instead was tried and REFUTED: the depth scan then gathers
         # the whole stacked array up front — +210% temp memory on
-        # grok-1-314b prefill. See EXPERIMENTS.md §Perf.)
+        # grok-1-314b prefill.)
         order = sorted(range(leaf.ndim), key=lambda i: -leaf.shape[i])
         for i in order:
             if entries[i] is None and leaf.shape[i] % axis_size == 0:
@@ -223,7 +223,7 @@ def cache_specs(cfg: ModelConfig, batch: int, *, multi_pod: bool,
     ``model`` axis whenever KV heads cannot shard it (kv % model != 0):
     flash-decode-style context parallelism. Without this, GSPMD replicates
     (all-gathers) the fp32-converted cache on every layer — 135 GB/device
-    per decoded token on yi-34b decode_32k (EXPERIMENTS.md §Perf).
+    per decoded token on yi-34b decode_32k.
     """
     dp = data_axes(multi_pod)
     total_dp = n_data * (2 if multi_pod else 1)

@@ -8,9 +8,8 @@ on the same clock.  Recording is always on; a span costs a few
 microseconds of host time.
 
 Spans nest through a per-thread stack: each record names its parent and
-its root, the outermost span of its call tree.  A callable handed to a
-worker thread runs under the span that handed it over when wrapped by
-`carry`.  Counts ride on the spans as attributes (``bytes=``,
+its root, the outermost span of its call tree.  A span opened on another
+thread starts a tree of its own.  Counts ride on the spans as attributes (``bytes=``,
 ``nodes=``); the body of a ``with`` may add them to the dict it yields.
 
 `records` and `totals` read the spans that lie inside an interval of
@@ -32,7 +31,7 @@ from typing import NamedTuple
 import jax
 
 __all__ = ["CAPACITY", "Recorder", "Span", "SpansDropped", "Total",
-           "RECORDER", "span", "carry", "records", "totals"]
+           "RECORDER", "span", "records", "totals"]
 
 CAPACITY = 65_536
 
@@ -107,23 +106,6 @@ class Recorder:
                         self._dropped_until = max(self._dropped_until, self._ring[0].t1)
                     self._ring.append(rec)
 
-    def carry(self, fn):
-        """``fn``, to run on another thread under this thread's current span."""
-        stack = self._stack()
-        if not stack:
-            return fn
-        top = stack[-1]
-
-        def run(*args, **kwargs):
-            mine = self._stack()
-            mine.append(top)
-            try:
-                return fn(*args, **kwargs)
-            finally:
-                mine.pop()
-
-        return run
-
     def records(self, since: float = -math.inf, until: float = math.inf) -> list[Span]:
         """The spans that started at or after ``since`` and ended by ``until``."""
         with self._lock:
@@ -154,6 +136,5 @@ class Recorder:
 #: The process's recorder, which the program's spans go to.
 RECORDER = Recorder()
 span = RECORDER.span
-carry = RECORDER.carry
 records = RECORDER.records
 totals = RECORDER.totals

@@ -69,7 +69,7 @@ def adamw_update(cfg: AdamWConfig, params: Any, grads: Any, state: dict):
     # NOTE: chunking this update over the layer-stack axis with lax.map was
     # tried and REFUTED (+36% temp on grok train — the map materializes
     # stacked fp32 ys instead of streaming); plain per-leaf updates fuse
-    # better. See EXPERIMENTS.md §Perf.
+    # better.
     upd = upd_one
 
     flat_p, tdef = jax.tree.flatten(params)

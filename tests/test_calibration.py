@@ -1,7 +1,7 @@
 """Calibration determinism, persistence, and staleness contracts.
 
 The calibrated-requirements path (`core.calibration`) only earns its
-place in the gated benchmarks if it is *deterministic*: the same
+place in the tests' pinned scenarios if it is *deterministic*: the same
 catalog + workloads must produce bit-identical requirement vectors
 across repeated runs and across the numpy / jax implementations, the
 JSON artifact must round-trip unchanged, and a stale artifact (taken
